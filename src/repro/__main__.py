@@ -39,7 +39,7 @@ import tempfile
 import numpy as np
 
 from .api import (SCHEMA_VERSION, FabricSpec, JobSpec, Session,
-                  SpecMismatchError, check_outputs, driver_parties, run_job)
+                  SpecMismatchError, driver_parties, run_job)
 from .core.transport import TransportError, pick_free_ports
 from .workloads import get as get_workload
 
@@ -212,6 +212,16 @@ def _load_outputs(path: str, protocol: str) -> dict:
             for tag, v in doc.items()}
 
 
+def _tpu_visible(env: dict) -> bool:
+    """Would a child process with ``env`` load the TPU library?  Decided
+    without touching JAX here: the parent must not claim the chip."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    import importlib.util
+    return importlib.util.find_spec("libtpu") is not None
+
+
 def cmd_fabric(args) -> int:
     """Launch one `run --worker K` process per global rank on localhost."""
     with open(os.path.join(args.jobdir, "job.json")) as f:
@@ -221,10 +231,18 @@ def cmd_fabric(args) -> int:
     if args.real and w.protocol == "gc":
         driver = "gc-2party"
     n_ranks = driver_parties(driver) * spec.num_workers
+    env = dict(os.environ)
+    if n_ranks > 1 and spec.exec_backend != "scalar" and _tpu_visible(env):
+        # a TPU belongs to one process: a second rank would fail on
+        # libtpu's lock or hang waiting for the chip
+        raise SystemExit(
+            f"error: fabric would start {n_ranks} {spec.exec_backend} ranks "
+            f"on one host, and each would claim the TPU; plan with "
+            f"--exec-backend scalar, run one rank per host, or set "
+            f"JAX_PLATFORMS=cpu")
     peers = ",".join(f"127.0.0.1:{p}" for p in pick_free_ports(n_ranks))
     print(f"fabric: {n_ranks} ranks ({driver}) over {peers}")
 
-    env = dict(os.environ)
     pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
     outputs: dict = {}
@@ -269,7 +287,7 @@ def cmd_fabric(args) -> int:
         _dump_outputs(args.json, outputs)
         print(f"wrote {args.json}")
     if args.check:
-        check_outputs(w, spec.n, outputs)
+        Session(spec).check(outputs)
         print("oracle check OK")
     return 0
 

@@ -184,7 +184,8 @@ def _gc_two_party_drivers(s: "Session", fx: Fabric
 def _ckks_drivers(s: "Session", fx: Fabric) -> dict[int, ProtocolDriver]:
     w, n, p = s.workload, s.spec.n, s.spec.num_workers
     params = s.ckks_params()
-    return {r: CkksDriver(params, w.inputs(n, r % p, p), seed=0xCEC5)
+    return {r: CkksDriver(params, w.inputs(n, r % p, p, ckks_params=params),
+                          seed=0xCEC5)
             for r in fx.hosted}
 
 
@@ -491,6 +492,18 @@ class Session:
             base, n_ring=self.spec.ckks_ring or base.n_ring,
             levels=self.spec.ckks_levels or base.levels)
 
+    def workload_extra(self) -> dict:
+        """What the workload's trace, inputs and oracle are built from
+        beyond the problem size: the CKKS parameters, for CKKS."""
+        if self.protocol == "ckks":
+            return {"ckks_params": self.ckks_params()}
+        return {}
+
+    def check(self, outputs: dict[int, np.ndarray]) -> None:
+        """Compare outputs against the workload's oracle for this spec."""
+        check_outputs(self.workload, self.spec.n, outputs,
+                      **self.workload_extra())
+
     def working_set(self, worker: int = 0) -> int:
         """Peak live pages of one worker's virtual trace (w of §2.4.3)."""
         if worker not in self._ws:
@@ -531,10 +544,8 @@ class Session:
                     self._adopt_trace(*got)
                     return self._progs
                 self.cache_events["trace"] = "miss"
-            extra = {}
-            if self.protocol == "ckks":
-                extra["ckks_params"] = self.ckks_params()
-            progs = self.workload.trace(spec.n, spec.num_workers, **extra)
+            progs = self.workload.trace(spec.n, spec.num_workers,
+                                        **self.workload_extra())
             if cache is not None:
                 self._adopt_trace(*cache.put_trace(
                     spec, self.workload, progs,
@@ -703,7 +714,7 @@ class Session:
         finally:
             fx.close()
         if check:
-            check_outputs(self.workload, spec.n, outputs)
+            self.check(outputs)
         return outputs
 
     def _batch_schedules(self, planned) -> list:
@@ -903,9 +914,10 @@ class Session:
 
 
 def check_outputs(w: Workload, n: int, outputs: dict[int, np.ndarray],
-                  atol: float = 2e-2) -> None:
-    """Compare executed outputs against the workload's numpy oracle."""
-    exp = w.oracle(n)
+                  atol: float = 2e-2, **extra) -> None:
+    """Compare executed outputs against the workload's numpy oracle;
+    ``extra`` is what the oracle is built from (``workload_extra``)."""
+    exp = w.oracle(n, **extra)
     missing = set(exp) - set(outputs)
     assert not missing, f"{w.name}: missing outputs {sorted(missing)[:5]}..."
     for tag, e in exp.items():
@@ -971,7 +983,8 @@ def estimate_job_resources(sess: Session) -> tuple[int, int]:
                                    for i in range(spec.num_workers))]
         frames_w = [c.num_frames for c in cfgs]
     frames = sum(frames_w)
-    page_bytes = (1 << sess.workload.page_shift) * SLOT_BYTES[sess.protocol]
+    page_shift = sess.workload.page_shift_for(**sess.workload_extra())
+    page_bytes = (1 << page_shift) * SLOT_BYTES[sess.protocol]
     parties = driver_parties(spec.driver) if spec.driver in DRIVERS else 1
     engine_bytes = frames * page_bytes * parties
     planner_bytes = sum(plan_memory_estimate(c, spec.chunk_instrs)

@@ -20,6 +20,7 @@ from __future__ import annotations
 import concurrent.futures as cf
 import dataclasses
 import itertools
+import multiprocessing
 import os
 import threading
 from typing import Any, Callable, Sequence
@@ -144,7 +145,11 @@ def plan_workers(progs: Sequence[Program], cfg: PlanConfig | Sequence[PlanConfig
                                    thread_name_prefix="mage-plan") as ex:
             results = list(ex.map(_plan_one, *args))
     else:
-        with cf.ProcessPoolExecutor(max_workers=len(progs)) as ex:
+        # spawn, never fork: a forked child would inherit a parent's
+        # claim on the accelerator (e.g. the serve daemon after a job ran)
+        with cf.ProcessPoolExecutor(
+                max_workers=len(progs),
+                mp_context=multiprocessing.get_context("spawn")) as ex:
             results = list(ex.map(_plan_one, *args))
     return [r[0] for r in results], [r[1] for r in results]
 

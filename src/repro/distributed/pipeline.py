@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models.blocks import block_train
@@ -63,10 +62,10 @@ def pipeline_loss(params, tokens, cfg: ModelConfig, mesh, n_micro: int,
     data_spec = P(("data",), None)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, data_spec),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(p, toks):
         stage = jax.lax.axis_index("pod")
         stack = jax.tree_util.tree_map(lambda x: x[0], p["groups"][0])

@@ -9,14 +9,21 @@
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 public API + layout adapters), ref.py (pure-jnp oracle).  Validated in
-interpret mode on CPU; TPU is the lowering target.
+interpret mode on CPU; compiled for the TPU by ``tests/test_tpu_compile.py``
+and run there by ``chip_smoke.py``.
+
+On the main path, the batched CKKS driver sends every ``CT_MUL_NR`` group's
+NTTs through ``ntt/`` when :func:`use_pallas` is true.  The batched GC
+driver sends groups of bare AND/OR instructions through ``garble/``, but no
+registered workload traces such a group yet: their word-level ops run on
+the numpy gates.
 
 Interpret-mode selection: compiled ``pallas_call`` cannot lower on the CPU
 backend, so every ops.py entry point defaults ``interpret=None`` and
 resolves it through :func:`resolve_interpret` — compiled when a real XLA
-accelerator backend is present, interpret otherwise.  Setting
-``REPRO_PALLAS_INTERPRET=1`` forces interpret mode everywhere (the escape
-hatch for debugging kernels on accelerator hosts).
+accelerator backend is present, interpret otherwise.  Tests that want the
+interpreter pass ``interpret=True`` themselves.  A JAX that fails to
+initialize raises; it never falls back to the CPU.
 """
 
 from __future__ import annotations
@@ -24,21 +31,37 @@ from __future__ import annotations
 import functools
 import os
 
+#: Where compiled kernels persist between runs when the environment names
+#: no ``JAX_COMPILATION_CACHE_DIR``: a fixed directory of the checkout, so
+#: a later run in the same tree finds them (the path is part of the key).
+CACHE_DIR = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                         "..", "..", "..", ".jax_cache"))
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at ``CACHE_DIR`` unless
+    ``JAX_COMPILATION_CACHE_DIR`` is set (JAX then reads it itself).
+    Returns the directory in effect.  Must run before the first compile."""
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
+
 
 @functools.lru_cache(maxsize=1)
 def _default_backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax import/init failure
-        return "cpu"
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        configure_compile_cache()
+    return backend
 
 
 def use_pallas() -> bool:
     """True when compiled ``pallas_call`` can actually lower here: a
-    non-CPU XLA backend is present and the escape hatch is not set."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET") == "1":
-        return False
+    non-CPU XLA backend is present."""
     return _default_backend() != "cpu"
 
 
@@ -50,4 +73,5 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 from . import garble, ntt, paged_attn  # noqa: E402
 
-__all__ = ["garble", "ntt", "paged_attn", "resolve_interpret", "use_pallas"]
+__all__ = ["CACHE_DIR", "configure_compile_cache", "garble", "ntt",
+           "paged_attn", "resolve_interpret", "use_pallas"]

@@ -3,6 +3,8 @@
 The protocol driver stores labels as (m, 2) uint64; the TPU kernel wants
 (m, 4) uint32 lanes.  ``interpret=None`` auto-selects: compiled on a real
 XLA backend, interpret mode on CPU (see ``kernels.resolve_interpret``).
+Gate ``i`` of a call is tweaked with ``2 * (gid0 + i)`` and ``+ 1`` as a
+64-bit id, exactly as the numpy gates do.
 """
 
 from __future__ import annotations
@@ -12,6 +14,8 @@ import numpy as np
 
 from .. import resolve_interpret
 from . import kernel, ref
+
+_U32 = (1 << 32) - 1
 
 
 def u64_to_u32(lbl: np.ndarray) -> np.ndarray:
@@ -31,10 +35,24 @@ def _pad(x: np.ndarray, block: int) -> tuple[np.ndarray, int]:
     return x, m
 
 
+def _tweak_words(gid0: int) -> tuple[np.uint32, np.uint32]:
+    """The first gate's 64-bit tweak ``2 * gid0`` as (low, high) words."""
+    t = 2 * int(gid0)
+    return np.uint32(t & _U32), np.uint32(t >> 32)
+
+
+def _ref_gid(gid0: int, m: int) -> int:
+    """The jnp oracle tweaks with int32 ids; refuse ids it cannot carry."""
+    if 2 * (int(gid0) + m) >= 1 << 31:
+        raise OverflowError(f"gate ids up to {gid0 + m} exceed the int32 "
+                            f"tweak of the reference path")
+    return 2 * int(gid0)
+
+
 def garble_and(a0_u64: np.ndarray, b0_u64: np.ndarray, r_u64: np.ndarray,
                gid0: int, *, use_kernel: bool = True,
                interpret: bool | None = None,
-               block_m: int = 64) -> tuple[np.ndarray, np.ndarray]:
+               block_m: int = kernel.BLOCK_M) -> tuple[np.ndarray, np.ndarray]:
     """Batch half-gates garble; uint64-pair API matching the driver.
 
     Returns (c0 (m,2) uint64, tables (m,4) uint64)."""
@@ -51,10 +69,10 @@ def garble_and(a0_u64: np.ndarray, b0_u64: np.ndarray, r_u64: np.ndarray,
     if use_kernel:
         c, tab = kernel.garble_and_pallas(
             jnp.asarray(a), jnp.asarray(b), jnp.asarray(r),
-            jnp.int32(2 * gid0), interpret=interpret, block_m=block_m)
+            *_tweak_words(gid0), interpret=interpret, block_m=block_m)
     else:
         c, tab = ref.garble_and(jnp.asarray(a), jnp.asarray(b),
-                                jnp.asarray(r), 2 * gid0)
+                                jnp.asarray(r), _ref_gid(gid0, len(a)))
     return (u32_to_u64(np.asarray(c))[:m],
             u32_to_u64(np.asarray(tab))[:m])
 
@@ -62,7 +80,7 @@ def garble_and(a0_u64: np.ndarray, b0_u64: np.ndarray, r_u64: np.ndarray,
 def eval_and(wa_u64: np.ndarray, wb_u64: np.ndarray, tables_u64: np.ndarray,
              gid0: int, *, use_kernel: bool = True,
              interpret: bool | None = None,
-             block_m: int = 64) -> np.ndarray:
+             block_m: int = kernel.BLOCK_M) -> np.ndarray:
     if len(wa_u64) == 0:
         return np.zeros((0, 2), dtype=np.uint64)
     interpret = resolve_interpret(interpret)
@@ -76,8 +94,8 @@ def eval_and(wa_u64: np.ndarray, wb_u64: np.ndarray, tables_u64: np.ndarray,
     if use_kernel:
         c = kernel.eval_and_pallas(
             jnp.asarray(wa), jnp.asarray(wb), jnp.asarray(tab),
-            jnp.int32(2 * gid0), interpret=interpret, block_m=block_m)
+            *_tweak_words(gid0), interpret=interpret, block_m=block_m)
     else:
         c = ref.eval_and(jnp.asarray(wa), jnp.asarray(wb), jnp.asarray(tab),
-                         2 * gid0)
+                         _ref_gid(gid0, len(wa)))
     return u32_to_u64(np.asarray(c))[:m]

@@ -16,10 +16,11 @@ import numpy as np
 
 from ...protocols.garbled.aes import ROUND_KEYS, SBOX, SHIFT_ROWS
 
-_SBOX = jnp.asarray(SBOX, dtype=jnp.int32)
-_SHIFT_ROWS = jnp.asarray(SHIFT_ROWS, dtype=jnp.int32)
+# host constants: importing this module must not touch a device
+_SBOX = SBOX.astype(np.int32)
+_SHIFT_ROWS = SHIFT_ROWS
 # round keys as (11, 16) int32 byte values
-_RK = jnp.asarray(ROUND_KEYS.astype(np.int32))
+_RK = ROUND_KEYS.astype(np.int32)
 
 
 def labels_to_bytes(lbl: jnp.ndarray) -> jnp.ndarray:

@@ -13,7 +13,7 @@ from ...protocols.ckks.ntt import ntt_tables
 
 def ntt_forward(a, q: int, psis_brv: np.ndarray):
     """a: (..., N) uint64 standard order -> bit-reversed NTT domain."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = jnp.asarray(np.asarray(a))
         n = a.shape[-1]
         qq = jnp.uint64(q)
@@ -35,7 +35,7 @@ def ntt_forward(a, q: int, psis_brv: np.ndarray):
 
 
 def ntt_inverse(a, q: int, psis_inv_brv: np.ndarray, n_inv: int):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = jnp.asarray(np.asarray(a))
         n = a.shape[-1]
         qq = jnp.uint64(q)
@@ -58,7 +58,7 @@ def ntt_inverse(a, q: int, psis_inv_brv: np.ndarray, n_inv: int):
 
 
 def pointwise_mul(a, b, q: int):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = jnp.asarray(np.asarray(a))
         b = jnp.asarray(np.asarray(b))
         return (a.astype(jnp.uint64) * b.astype(jnp.uint64)) % jnp.uint64(q)

@@ -18,6 +18,7 @@ import numpy as np
 
 from ..configs import get_config, reduced_config
 from ..distributed.sharding import default_rules, use_rules
+from .mesh import make_mesh
 from ..models import init_lm, lm_prefill
 from ..serve.paged_kv import plan_kv_schedule
 from ..serve.serve_step import Batcher, Request, serve_step
@@ -84,7 +85,7 @@ def main():
                     prompt=rng.integers(0, cfg.vocab_size, args.prompt_len),
                     max_new=args.max_new)
             for i in range(args.requests)]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, use_rules(default_rules(mesh)):
         total, dt = run_server(cfg, reqs, batch_size=2,
                                max_seq=args.prompt_len + args.max_new + 1,

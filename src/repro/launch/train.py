@@ -20,6 +20,7 @@ import jax
 from ..configs import get_config, reduced_config
 from ..data.pipeline import DataConfig, Prefetcher
 from ..distributed.sharding import default_rules, use_rules
+from .mesh import make_mesh
 from ..models import ModelConfig
 from ..train import checkpoint as ckpt
 from ..train.fault import FaultConfig, Preemption, RunReport, StepTimer, is_bad
@@ -134,7 +135,7 @@ def main():
                       vocab_size=cfg.vocab_size,
                       frames_dim=cfg.d_model if cfg.is_encdec else 0)
     fcfg = FaultConfig(checkpoint_every=max(args.steps // 4, 5))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mesh, use_rules(default_rules(mesh)):
         report = train_loop(cfg, tcfg, dcfg, fcfg, args.steps,
                             ckpt_dir=args.ckpt_dir)

@@ -9,7 +9,7 @@ import numpy as np
 
 from ..core.bytecode import Op
 from ..core.workers import ProgramOptions
-from ..protocols.ckks import Batch, Plain
+from ..protocols.ckks import Batch, CkksParams, Plain
 from ..protocols.garbled.dsl import Integer, Party
 from .base import CKKS_PAGE_SHIFT, GC_PAGE_SHIFT, Workload, register
 from .ckks_workloads import PARAMS, _provider
@@ -135,23 +135,25 @@ def _pir_build(opts: ProgramOptions) -> None:
         acc.mark_output(OUT_TAGS + k)
 
 
-def _pir_data(n: int):
+def _pir_data(n: int, slots: int):
     rng = np.random.default_rng(8200 + n)
     r, c = _pir_grid(n)
-    db = rng.uniform(-1, 1, (r * c, PARAMS.slots))
+    db = rng.uniform(-1, 1, (r * c, slots))
     target = int(rng.integers(0, r))
-    q = np.zeros((r, PARAMS.slots))
+    q = np.zeros((r, slots))
     q[target] = 1.0
     return db, q, target
 
 
-def _pir_inputs(n: int, worker: int, p: int):
-    db, q, _ = _pir_data(n)
+def _pir_inputs(n: int, worker: int, p: int,
+                ckks_params: CkksParams = PARAMS):
+    db, q, _ = _pir_data(n, ckks_params.slots)
     return _provider({A_TAGS: db, Q_TAGS: q})
 
 
-def _pir_oracle(n: int) -> dict[int, np.ndarray]:
-    db, q, target = _pir_data(n)
+def _pir_oracle(n: int, ckks_params: CkksParams = PARAMS
+                ) -> dict[int, np.ndarray]:
+    db, q, target = _pir_data(n, ckks_params.slots)
     r, c = _pir_grid(n)
     return {OUT_TAGS + k: db[target * c + k] for k in range(c)}
 

@@ -39,10 +39,20 @@ class Workload:
     def trace(self, n: int | None = None, num_workers: int = 1,
               **extra) -> list[Program]:
         n = n or self.default_n
+        extra = {**self.params, **extra}
         return trace_workers(self.build, protocol=self.protocol,
-                             page_shift=self.page_shift,
+                             page_shift=self.page_shift_for(**extra),
                              num_workers=num_workers, problem_size=n,
-                             extra={**self.params, **extra})
+                             extra=extra)
+
+    def page_shift_for(self, ckks_params=None, **_) -> int:
+        """Page size of a trace under ``extra``: a CKKS page grows until a
+        top-level product ciphertext fits, since values never straddle
+        pages; rings up to N=1024 keep the 2^14-slot page."""
+        if ckks_params is None:
+            return self.page_shift
+        need = ckks_params.ct_slots(ckks_params.levels, ncomp=3)
+        return max(self.page_shift, (need - 1).bit_length())
 
 
 REGISTRY: dict[str, Workload] = {}

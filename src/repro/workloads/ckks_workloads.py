@@ -24,6 +24,8 @@ C_TAGS = 1 << 22          # plaintext constants
 OUT_TAGS = 1 << 24
 
 PARAMS = CkksParams(n_ring=128, levels=2)   # tests; benches override n_ring
+# Inputs and oracles take the session's ``ckks_params`` (the same object the
+# trace gets in ``ProgramOptions.extra``), so every ring draws its own data.
 
 
 def _params(opts_or_extra) -> CkksParams:
@@ -82,12 +84,14 @@ def _rsum_build(opts: ProgramOptions) -> None:
         acc.mark_output(OUT_TAGS)
 
 
-def _rsum_inputs(n: int, worker: int, p: int):
-    return _provider({X_TAGS: _vals(n, 7000 + n, PARAMS.slots)})
+def _rsum_inputs(n: int, worker: int, p: int,
+                 ckks_params: CkksParams = PARAMS):
+    return _provider({X_TAGS: _vals(n, 7000 + n, ckks_params.slots)})
 
 
-def _rsum_oracle(n: int) -> dict[int, np.ndarray]:
-    return {OUT_TAGS: _vals(n, 7000 + n, PARAMS.slots).sum(axis=0)}
+def _rsum_oracle(n: int, ckks_params: CkksParams = PARAMS
+                 ) -> dict[int, np.ndarray]:
+    return {OUT_TAGS: _vals(n, 7000 + n, ckks_params.slots).sum(axis=0)}
 
 
 register(Workload("rsum", "ckks", _rsum_build, _rsum_inputs, _rsum_oracle,
@@ -125,14 +129,16 @@ def _rstats_build(opts: ProgramOptions) -> None:
     var.mark_output(OUT_TAGS + 1)
 
 
-def _rstats_inputs(n: int, worker: int, p: int):
-    xs = _vals(n, 7100 + n, PARAMS.slots)
-    const = np.full(PARAMS.slots, 1.0 / n)
+def _rstats_inputs(n: int, worker: int, p: int,
+                   ckks_params: CkksParams = PARAMS):
+    xs = _vals(n, 7100 + n, ckks_params.slots)
+    const = np.full(ckks_params.slots, 1.0 / n)
     return _provider({X_TAGS: xs, C_TAGS: const[None, :]})
 
 
-def _rstats_oracle(n: int) -> dict[int, np.ndarray]:
-    xs = _vals(n, 7100 + n, PARAMS.slots)
+def _rstats_oracle(n: int, ckks_params: CkksParams = PARAMS
+                   ) -> dict[int, np.ndarray]:
+    xs = _vals(n, 7100 + n, ckks_params.slots)
     return {OUT_TAGS: xs.mean(axis=0),
             OUT_TAGS + 1: xs.var(axis=0)}
 
@@ -164,21 +170,23 @@ def _rmvmul_build(opts: ProgramOptions) -> None:
         acc.relin().mark_output(OUT_TAGS + i)
 
 
-def _rmvmul_data(n: int):
-    return (_vals(n * n, 7200 + n, PARAMS.slots),
-            _vals(n, 7300 + n, PARAMS.slots))
+def _rmvmul_data(n: int, slots: int):
+    return (_vals(n * n, 7200 + n, slots),
+            _vals(n, 7300 + n, slots))
 
 
-def _rmvmul_inputs(n: int, worker: int, p: int):
-    M, v = _rmvmul_data(n)
+def _rmvmul_inputs(n: int, worker: int, p: int,
+                   ckks_params: CkksParams = PARAMS):
+    M, v = _rmvmul_data(n, ckks_params.slots)
     return _provider({X_TAGS: M, Y_TAGS: v})
 
 
-def _rmvmul_oracle(n: int) -> dict[int, np.ndarray]:
-    M, v = _rmvmul_data(n)
+def _rmvmul_oracle(n: int, ckks_params: CkksParams = PARAMS
+                   ) -> dict[int, np.ndarray]:
+    M, v = _rmvmul_data(n, ckks_params.slots)
     out = {}
     for i in range(n):
-        acc = np.zeros(PARAMS.slots)
+        acc = np.zeros(ckks_params.slots)
         for j in range(n):
             acc += M[i * n + j] * v[j]
         out[OUT_TAGS + i] = acc
@@ -194,22 +202,24 @@ register(Workload("rmvmul", "ckks", _rmvmul_build, _rmvmul_inputs,
 # ---------------------------------------------------------------------------
 
 
-def _matmul_data(n: int):
-    return (_vals(n * n, 7400 + n, PARAMS.slots),
-            _vals(n * n, 7500 + n, PARAMS.slots))
+def _matmul_data(n: int, slots: int):
+    return (_vals(n * n, 7400 + n, slots),
+            _vals(n * n, 7500 + n, slots))
 
 
-def _matmul_inputs(n: int, worker: int, p: int):
-    A, B = _matmul_data(n)
+def _matmul_inputs(n: int, worker: int, p: int,
+                   ckks_params: CkksParams = PARAMS):
+    A, B = _matmul_data(n, ckks_params.slots)
     return _provider({X_TAGS: A, Y_TAGS: B})
 
 
-def _matmul_oracle(n: int) -> dict[int, np.ndarray]:
-    A, B = _matmul_data(n)
+def _matmul_oracle(n: int, ckks_params: CkksParams = PARAMS
+                   ) -> dict[int, np.ndarray]:
+    A, B = _matmul_data(n, ckks_params.slots)
     out = {}
     for i in range(n):
         for k in range(n):
-            acc = np.zeros(PARAMS.slots)
+            acc = np.zeros(ckks_params.slots)
             for j in range(n):
                 acc += A[i * n + j] * B[j * n + k]
             out[OUT_TAGS + i * n + k] = acc

@@ -160,6 +160,22 @@ def test_batched_matches_scalar_ckks():
     assert sum(s.batched_instructions for s in stats) > 0
 
 
+def test_ckks_executes_at_the_spec_ring():
+    # inputs, oracle and pages follow the spec's ring, not the N=128 default
+    stats = _check_equal(workload="n_rmatmul", n=4, ckks_ring=1024,
+                         memory_budget=0.4)
+    assert sum(s.directives for s in stats) > 0
+
+
+@pytest.mark.parametrize("ring,shift", [(128, 14), (1024, 14), (4096, 16)])
+def test_ckks_page_holds_a_product_ciphertext(ring, shift):
+    sess = Session(JobSpec(workload="n_rmatmul", n=2, ckks_ring=ring,
+                           memory_budget=0.4))
+    prog = sess.trace()[0]
+    assert prog.page_shift == shift
+    assert prog.page_slots >= sess.ckks_params().ct_slots(2, ncomp=3)
+
+
 def test_batched_matches_scalar_two_workers_net():
     # NET_SEND/NET_RECV barriers interleave the two workers' programs;
     # the schedules must keep that traffic in program order

@@ -70,6 +70,32 @@ def test_garble_ops_match_driver_gates():
     assert np.array_equal(t_ops, tab)
 
 
+@pytest.mark.parametrize("gid0", [(1 << 31) - 20, (1 << 32) - 3])
+def test_garble_ops_carry_64bit_gate_ids(gid0):
+    # the numpy gates tweak with int64 ids; past 2^31 the kernel must carry
+    # the same id into the label's second word, never wrap at 32 bits
+    from repro.protocols.garbled.gates import (EvaluatorGates, GarblerGates,
+                                               PartyChannel)
+    m = 64
+    ch = PartyChannel()
+    g = GarblerGates(ch, seed=3)
+    g.gid = gid0
+    A, B = g._fresh(m), g._fresh(m)
+    C = g.and_(A.copy(), B.copy())
+    tab = ch.recv("tab")
+    c_ops, t_ops = gops.garble_and(A, B, g.R, gid0, block_m=32)
+    assert np.array_equal(c_ops, C)
+    assert np.array_equal(t_ops, tab)
+    e = EvaluatorGates(ch)
+    e.gid = gid0
+    ch.send("tab", tab)
+    wa = A ^ g.R[None]
+    assert np.array_equal(gops.eval_and(wa, B, tab, gid0, block_m=32),
+                          e.and_(wa, B))
+    with pytest.raises(OverflowError):   # the int32 oracle refuses instead
+        gops.garble_and(A, B, g.R, gid0, use_kernel=False, block_m=32)
+
+
 # ---------------------------------------------------------------------------
 # ntt kernel
 # ---------------------------------------------------------------------------
@@ -198,3 +224,43 @@ def test_paged_attention_bf16():
         np.asarray(vp, dtype=np.float32), bt, sl))
     out_k = np.asarray(pops.paged_decode_attention(q, kp, vp, bt, sl))
     np.testing.assert_allclose(out_k, out_ref, rtol=2e-2, atol=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# process hygiene: importing claims no device; the compile cache location
+# ---------------------------------------------------------------------------
+
+
+def _python(code: str, **env) -> str:
+    import os
+    import subprocess
+    import sys
+    full = {**os.environ, "PYTHONPATH": os.path.join(
+        os.path.dirname(__file__), "..", "src"), **env}
+    full = {k: v for k, v in full.items() if v is not None}
+    return subprocess.run([sys.executable, "-c", code], env=full, check=True,
+                          capture_output=True, text=True).stdout.strip()
+
+
+def test_import_allocates_nothing_on_a_device():
+    out = _python("import repro.api, repro.exec, jax; "
+                  "print(len(jax.live_arrays()))")
+    assert out == "0"
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_location(tmp_path, env_dir):
+    import os
+    from repro.kernels import CACHE_DIR
+    given = str(tmp_path / "cache") if env_dir else None
+    out = _python("import jax, repro.kernels as k; "
+                  "print(k.configure_compile_cache()); "
+                  "print(jax.config.jax_compilation_cache_dir)",
+                  JAX_COMPILATION_CACHE_DIR=given)
+    chosen, in_effect = out.splitlines()
+    if given:
+        assert chosen == in_effect == given   # JAX reads the variable
+    else:
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+        assert chosen == in_effect == CACHE_DIR == \
+            os.path.join(root, ".jax_cache")

@@ -18,12 +18,13 @@ from repro.configs import reduced_config
 from repro.models import init_lm, lm_loss
 from repro.distributed.pipeline import pipeline_loss, split_stage_params
 from repro.distributed.sharding import rules_for
+from repro.launch.mesh import make_mesh
 
 cfg = dataclasses.replace(reduced_config("stablelm-3b"), n_layers=4)
 params = init_lm(jax.random.PRNGKey(0), cfg)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab_size)
 ref = float(lm_loss(params, toks, cfg, aux_weight=0.0)[0])
-mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 1), ("pod", "data", "model"))
 rules = rules_for(cfg, mesh)
 staged = split_stage_params(params, n_stages=2)
 with mesh:

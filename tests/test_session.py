@@ -158,6 +158,29 @@ def test_cli_plan_run_roundtrip_and_tamper_rejection(tmp_path, capsys):
     assert ei.value.code == 2
 
 
+def test_cli_fabric_refuses_two_tpu_ranks(tmp_path, monkeypatch):
+    # every batched rank would claim the one TPU: fail before spawning
+    import repro.__main__ as cli
+    job = tmp_path / "job"
+    assert main(["plan", "--workload", "merge", "-n", "64", "--workers",
+                 "2", "--budget", "10", "--exec-backend", "batched",
+                 "--out", str(job)]) == 0
+    monkeypatch.setattr(cli, "_tpu_visible", lambda env: True)
+    monkeypatch.setattr(cli.subprocess, "Popen", None)   # must not spawn
+    with pytest.raises(SystemExit, match="claim the TPU"):
+        main(["fabric", str(job)])
+
+
+@pytest.mark.parametrize("platforms,visible", [("cpu", False),
+                                               ("tpu,cpu", None)])
+def test_tpu_visible_follows_jax_platforms(platforms, visible):
+    import importlib.util
+    from repro.__main__ import _tpu_visible
+    if visible is None:
+        visible = importlib.util.find_spec("libtpu") is not None
+    assert _tpu_visible({"JAX_PLATFORMS": platforms}) is visible
+
+
 def test_from_plan_rejects_foreign_program_file(tmp_path):
     a, b = tmp_path / "a", tmp_path / "b"
     for d, n in ((a, 128), (b, 64)):
