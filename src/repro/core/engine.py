@@ -18,10 +18,12 @@ The engine runs programs in any phase:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import numpy as np
 
+from .. import obs
 from .bytecode import (_IMM_OFF, _IN_OFF, _OUT_OFF, Instr, Op, Program,
                        ProgramFile, decode_chunk, iter_instructions,
                        iter_record_chunks, unpack_heads)
@@ -61,8 +63,6 @@ class EngineStats:
     directives: int = 0
     io_read_bytes: int = 0
     io_write_bytes: int = 0
-    finish_in_waits: int = 0
-    finish_out_waits: int = 0
     net_messages: int = 0
     net_sent_bytes: int = 0
     net_recv_bytes: int = 0
@@ -70,6 +70,9 @@ class EngineStats:
     batched_instructions: int = 0
     #: number of execute_batch calls those instructions collapsed into
     batches: int = 0
+    #: instructions whose op is in the driver's batch_ops that still ran
+    #: one by one (singleton or scalar groups of the batched/overlap loops)
+    batchable_scalar: int = 0
     #: NET_RECVs posted as deferred completion handles (overlap backend)
     posted_recvs: int = 0
     #: peak simultaneously outstanding recv handles (overlap backend)
@@ -84,6 +87,23 @@ class EngineStats:
         link = self.net_links.setdefault((src, dst), [0, 0])
         link[0] += 1
         link[1] += nbytes
+
+
+#: swap directives: executed by the engine itself, against its storage
+_SWAPS = frozenset({Op.SWAP_IN, Op.SWAP_OUT, Op.ISSUE_SWAP_IN,
+                    Op.FINISH_SWAP_IN, Op.COPY_OUT, Op.ISSUE_SWAP_OUT,
+                    Op.FINISH_SWAP_OUT})
+_WAIT = "storage.wait"
+
+
+@functools.lru_cache(maxsize=None)
+def op_spans(prefix: str) -> dict[Op, str]:
+    """Span names ``<prefix>.<OP>`` of every op, built once per prefix."""
+    return {op: f"{prefix}.{op.name}" for op in Op}
+
+
+_STORAGE_SPANS = op_spans("storage")
+_BATCHED_SPANS = op_spans("batched")
 
 
 class Engine:
@@ -121,6 +141,8 @@ class Engine:
         self._slot_future: dict[int, Any] = {}
         self.stats = EngineStats()
         self._page_shape = page_shape
+        # a batched driver runs its scalar calls on the driver it wraps
+        self._drv_spans = op_spans(getattr(driver, "inner", driver).name)
 
     # -- helpers ---------------------------------------------------------------
 
@@ -134,8 +156,13 @@ class Engine:
 
     def _wait_slot(self, slot: int) -> None:
         fut = self._slot_future.pop(slot, None)
-        if fut is not None:
+        if fut is None:
+            return
+        if fut.done():
             fut.result()
+        else:
+            with obs.span(_WAIT):
+                fut.result()
 
     def _instructions(self):
         return iter_instructions(self.prog)
@@ -154,13 +181,14 @@ class Engine:
         # try/finally: a mid-run driver/storage exception must not leak the
         # AsyncIO thread pool or an open (possibly temp-file) backend.
         try:
-            if self.overlap_schedule is not None:
-                self._run_loop_overlap(on_output)
-            elif self.batch_schedule is not None \
-                    and hasattr(self.driver, "execute_batch"):
-                self._run_loop_batched(on_output)
-            else:
-                self._run_loop(on_output)
+            with obs.span("engine.run"):
+                if self.overlap_schedule is not None:
+                    self._run_loop_overlap(on_output)
+                elif self.batch_schedule is not None \
+                        and hasattr(self.driver, "execute_batch"):
+                    self._run_loop_batched(on_output)
+                else:
+                    self._run_loop(on_output)
         finally:
             self.stats.io_read_bytes = self.io.bytes_read
             self.stats.io_write_bytes = self.io.bytes_written
@@ -197,12 +225,8 @@ class Engine:
                 if gop >= 0 and len(rows) >= 2 and rec is not None \
                         and Op(gop) in batch_ops:
                     self._exec_batch(Op(gop), rec, rows)
-                elif instrs is not None:
-                    for r in rows:
-                        self._exec_one(instrs[r], on_output)
                 else:
-                    for ins in decode_chunk(rec[rows]):
-                        self._exec_one(ins, on_output)
+                    self._exec_rows(rec, instrs, rows, batch_ops, on_output)
             ci += 1
         drv.finalize()
 
@@ -250,12 +274,9 @@ class Engine:
                     if gop >= 0 and len(rows) >= 2 and rec is not None \
                             and Op(gop) in batch_ops:
                         self._exec_batch(Op(gop), rec, rows)
-                    elif instrs is not None:
-                        for r in rows:
-                            self._exec_one(instrs[r], on_output)
                     else:
-                        for ins in decode_chunk(rec[rows]):
-                            self._exec_one(ins, on_output)
+                        self._exec_rows(rec, instrs, rows, batch_ops,
+                                        on_output)
                 elif kind == K_SEND:
                     net = self._net()
                     for r in rows:
@@ -289,6 +310,16 @@ class Engine:
             ci += 1
         drv.finalize()
 
+    def _exec_rows(self, rec, instrs, rows, batch_ops, on_output) -> None:
+        """A group's rows one by one, in stored order, through
+        ``_exec_one``; those the driver could have batched are counted."""
+        seq = ((instrs[r] for r in rows) if instrs is not None
+               else decode_chunk(rec[rows]))
+        for ins in seq:
+            if ins.op in batch_ops:
+                self.stats.batchable_scalar += 1
+            self._exec_one(ins, on_output)
+
     def _exec_batch(self, op: Op, rec: np.ndarray, rows: np.ndarray) -> None:
         r0 = rec[rows[0]]
         _, n_outs, n_ins, n_imm = unpack_heads(r0[0])
@@ -297,84 +328,90 @@ class Engine:
                     int(r0[_OUT_OFF + 1 + 2 * j])) for j in range(n_outs)]
         in_idx = [(rec[rows, _IN_OFF + 2 * j],
                    int(r0[_IN_OFF + 1 + 2 * j])) for j in range(n_ins)]
-        self.driver.execute_batch(op, imm, out_idx, in_idx, self.memory)
+        with obs.span(_BATCHED_SPANS[op]):
+            self.driver.execute_batch(op, imm, out_idx, in_idx, self.memory)
         self.stats.instructions += len(rows)
         self.stats.batched_instructions += len(rows)
         self.stats.batches += 1
 
+    def _swap(self, instr: Instr) -> None:
+        """One swap directive; only waiting on the storage is
+        ``storage.wait``."""
+        self.stats.directives += 1
+        op = instr.op
+        if op == Op.SWAP_IN:
+            fut = self.io.issue_read(instr.imm[0],
+                                     self._frame_page(instr.outs[0]))
+            with obs.span(_WAIT):
+                fut.result()
+        elif op == Op.SWAP_OUT:
+            fut = self.io.issue_write(
+                instr.imm[0],
+                np.array(self._frame_page(instr.ins[0]), copy=True))
+            with obs.span(_WAIT):
+                fut.result()
+        elif op == Op.ISSUE_SWAP_IN:
+            vpage, slot = instr.imm
+            self._wait_slot(slot)
+            self._slot_future[slot] = self.io.issue_read(vpage,
+                                                         self.pf[slot])
+        elif op == Op.FINISH_SWAP_IN:
+            slot = instr.imm[1]
+            self._wait_slot(slot)
+            self._frame_page(instr.outs[0])[...] = self.pf[slot]
+        elif op == Op.COPY_OUT:
+            slot = instr.imm[0]
+            self._wait_slot(slot)
+            self.pf[slot][...] = self._frame_page(instr.ins[0])
+        elif op == Op.ISSUE_SWAP_OUT:
+            vpage, slot = instr.imm
+            self._slot_future[slot] = self.io.issue_write(vpage,
+                                                          self.pf[slot])
+        else:  # FINISH_SWAP_OUT
+            self._wait_slot(instr.imm[0])
+
     def _exec_one(self, instr: Instr, on_output) -> None:
         drv = self.driver
         w = self.prog.worker
-        if True:
-            op = instr.op
-            if op == Op.SWAP_IN:
-                self.stats.directives += 1
-                self.io.issue_read(instr.imm[0],
-                                   self._frame_page(instr.outs[0])).result()
-            elif op == Op.SWAP_OUT:
-                self.stats.directives += 1
-                self.io.issue_write(instr.imm[0],
-                                    np.array(self._frame_page(instr.ins[0]),
-                                             copy=True)).result()
-            elif op == Op.ISSUE_SWAP_IN:
-                self.stats.directives += 1
-                vpage, slot = instr.imm
-                self._wait_slot(slot)
-                self._slot_future[slot] = self.io.issue_read(
-                    vpage, self.pf[slot])
-            elif op == Op.FINISH_SWAP_IN:
-                self.stats.directives += 1
-                vpage, slot = instr.imm[0], instr.imm[1]
-                self._wait_slot(slot)
-                self.stats.finish_in_waits += 1
-                self._frame_page(instr.outs[0])[...] = self.pf[slot]
-            elif op == Op.COPY_OUT:
-                self.stats.directives += 1
-                slot = instr.imm[0]
-                self._wait_slot(slot)
-                self.pf[slot][...] = self._frame_page(instr.ins[0])
-            elif op == Op.ISSUE_SWAP_OUT:
-                self.stats.directives += 1
-                vpage, slot = instr.imm
-                self._slot_future[slot] = self.io.issue_write(
-                    vpage, self.pf[slot])
-            elif op == Op.FINISH_SWAP_OUT:
-                self.stats.directives += 1
-                self._wait_slot(instr.imm[0])
-                self.stats.finish_out_waits += 1
-            elif op == Op.NET_SEND:
-                self.stats.directives += 1
-                dst, tag = instr.imm[0], instr.imm[1]
-                view = self._view(instr.ins[0])
-                self._net().send(w, dst, tag, view)
-                self.stats.net_messages += 1
-                self.stats.net_sent_bytes += view.nbytes
-                self.stats._net_count(w, dst, view.nbytes)
-            elif op == Op.NET_RECV:
-                self.stats.directives += 1
-                src, tag = instr.imm[0], instr.imm[1]
-                view = self._view(instr.outs[0])
-                self._net().recv(src, w, tag, out=view)
-                self.stats.net_messages += 1
-                self.stats.net_recv_bytes += view.nbytes
-                self.stats._net_count(src, w, view.nbytes)
-            elif op == Op.NET_BARRIER:
-                # documented as "wait until posted send/recv with tag done"
-                # (bytecode.py) — this engine's NET ops are synchronous, so
-                # the completion wait is a no-op.  Collective sync is the
-                # fabric's job (PartyView.barrier / Fabric.barrier), not an
-                # instruction semantic.
-                self.stats.directives += 1
-            elif op == Op.FREE:
-                pass
-            elif op == Op.OUTPUT:
-                self.stats.instructions += 1
-                views = [self._view(s) for s in instr.ins]
+        op = instr.op
+        if op in _SWAPS:
+            with obs.span(_STORAGE_SPANS[op]):
+                self._swap(instr)
+        elif op == Op.NET_SEND:
+            self.stats.directives += 1
+            dst, tag = instr.imm[0], instr.imm[1]
+            view = self._view(instr.ins[0])
+            self._net().send(w, dst, tag, view)
+            self.stats.net_messages += 1
+            self.stats.net_sent_bytes += view.nbytes
+            self.stats._net_count(w, dst, view.nbytes)
+        elif op == Op.NET_RECV:
+            self.stats.directives += 1
+            src, tag = instr.imm[0], instr.imm[1]
+            view = self._view(instr.outs[0])
+            self._net().recv(src, w, tag, out=view)
+            self.stats.net_messages += 1
+            self.stats.net_recv_bytes += view.nbytes
+            self.stats._net_count(src, w, view.nbytes)
+        elif op == Op.NET_BARRIER:
+            # documented as "wait until posted send/recv with tag done"
+            # (bytecode.py) — this engine's NET ops are synchronous, so
+            # the completion wait is a no-op.  Collective sync is the
+            # fabric's job (PartyView.barrier / Fabric.barrier), not an
+            # instruction semantic.
+            self.stats.directives += 1
+        elif op == Op.FREE:
+            pass
+        elif op == Op.OUTPUT:
+            self.stats.instructions += 1
+            views = [self._view(s) for s in instr.ins]
+            with obs.span(self._drv_spans[op]):
                 drv.execute(op, instr.imm, [], views)
-                if on_output is not None:
-                    on_output(instr, views)
-            else:
-                self.stats.instructions += 1
+            if on_output is not None:
+                on_output(instr, views)
+        else:
+            self.stats.instructions += 1
+            with obs.span(self._drv_spans[op]):
                 drv.execute(op, instr.imm,
                             [self._view(s) for s in instr.outs],
                             [self._view(s) for s in instr.ins])

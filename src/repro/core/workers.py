@@ -18,6 +18,7 @@ it here, so thread spawning and error collection live in exactly one place.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import contextvars
 import dataclasses
 import itertools
 import multiprocessing
@@ -205,7 +206,10 @@ def run_engines(jobs: Sequence[EngineJob],
     if len(jobs) == 1:
         _run(0, jobs[0])
     else:
-        threads = [threading.Thread(target=_run, args=(k, job), daemon=True)
+        # each engine thread runs in a copy of the caller's context, so
+        # what it records carries the caller's job (repro.obs)
+        threads = [threading.Thread(target=contextvars.copy_context().run,
+                                    args=(_run, k, job), daemon=True)
                    for k, job in enumerate(jobs)]
         for t in threads:
             t.start()

@@ -4,12 +4,17 @@ The CKKS driver keeps polynomials as uint64 (numpy hot path); the TPU
 kernel wants uint32 (q < 2^30 so coefficients fit).  Tables come from the
 shared protocols/ckks/ntt.py cache, so all three implementations use the
 same twiddle ordering.
+
+Each launch counts what crosses between host and device (``repro.obs``):
+``ntt.h2d_bytes`` for the padded input and the table sent, ``ntt.d2h_bytes``
+for the output read back, and ``ntt.launches`` for the transforms.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from ... import obs
 from .. import resolve_interpret
 from . import kernel
 from ...protocols.ckks.ntt import ntt_tables
@@ -23,27 +28,37 @@ def _pad(a: np.ndarray, block: int) -> tuple[np.ndarray, int]:
     return a, b
 
 
+def _launch(a32: np.ndarray, psis32: np.ndarray, **kw) -> np.ndarray:
+    """One transform launch, read back to the host."""
+    obs.count("ntt.launches", 1)
+    obs.count("ntt.h2d_bytes", a32.nbytes + psis32.nbytes)
+    out = np.asarray(kernel.ntt_pallas(a32, psis32, **kw))
+    obs.count("ntt.d2h_bytes", out.nbytes)
+    return out
+
+
 def ntt_forward(a_u64: np.ndarray, q: int, *, interpret: bool | None = None,
                 block_b: int = 8) -> np.ndarray:
     """(B, N) uint64 coefficients -> bit-reversed NTT domain, via Pallas."""
-    interpret = resolve_interpret(interpret)
-    psis, _, _ = ntt_tables(q, a_u64.shape[-1])
-    a32, b = _pad(a_u64.astype(np.uint32), block_b)
-    out = kernel.ntt_pallas(a32, psis.astype(np.uint32), q=q,
-                            interpret=interpret, block_b=block_b)
-    return np.asarray(out)[:b].astype(np.uint64)
+    with obs.span("ntt.forward"):
+        interpret = resolve_interpret(interpret)
+        psis, _, _ = ntt_tables(q, a_u64.shape[-1])
+        a32, b = _pad(a_u64.astype(np.uint32), block_b)
+        out = _launch(a32, psis.astype(np.uint32), q=q,
+                      interpret=interpret, block_b=block_b)
+        return out[:b].astype(np.uint64)
 
 
 def ntt_inverse(a_u64: np.ndarray, q: int, *, interpret: bool | None = None,
                 block_b: int = 8) -> np.ndarray:
-    interpret = resolve_interpret(interpret)
-    n = a_u64.shape[-1]
-    _, psis_inv, n_inv = ntt_tables(q, n)
-    a32, b = _pad(a_u64.astype(np.uint32), block_b)
-    out = kernel.ntt_pallas(a32, psis_inv.astype(np.uint32), q=q,
-                            inverse=True, n_inv=int(n_inv),
-                            interpret=interpret, block_b=block_b)
-    return np.asarray(out)[:b].astype(np.uint64)
+    with obs.span("ntt.inverse"):
+        interpret = resolve_interpret(interpret)
+        n = a_u64.shape[-1]
+        _, psis_inv, n_inv = ntt_tables(q, n)
+        a32, b = _pad(a_u64.astype(np.uint32), block_b)
+        out = _launch(a32, psis_inv.astype(np.uint32), q=q, inverse=True,
+                      n_inv=int(n_inv), interpret=interpret, block_b=block_b)
+        return out[:b].astype(np.uint64)
 
 
 def negacyclic_mul(a_u64: np.ndarray, b_u64: np.ndarray, q: int, *,
@@ -55,7 +70,9 @@ def negacyclic_mul(a_u64: np.ndarray, b_u64: np.ndarray, q: int, *,
     fb = ntt_forward(b_u64, q, interpret=interpret, block_b=block_b)
     fa32, bb = _pad(fa.astype(np.uint32), block_b)
     fb32, _ = _pad(fb.astype(np.uint32), block_b)
-    prod = kernel.pointwise_mul_pallas(fa32, fb32, q=q, interpret=interpret,
-                                       block_b=block_b)
-    return ntt_inverse(np.asarray(prod)[:bb].astype(np.uint64), q,
+    obs.count("ntt.h2d_bytes", fa32.nbytes + fb32.nbytes)
+    prod = np.asarray(kernel.pointwise_mul_pallas(
+        fa32, fb32, q=q, interpret=interpret, block_b=block_b))
+    obs.count("ntt.d2h_bytes", prod.nbytes)
+    return ntt_inverse(prod[:bb].astype(np.uint64), q,
                        interpret=interpret, block_b=block_b)

@@ -15,6 +15,14 @@ slowest §8.2 stage).  The expensive stages (planning + execution) only
 run under an admission reservation sized by the job's resolved frame
 count, so concurrent tenants cannot overcommit the shared frame pool.
 
+A submit is one job: its id is drawn when the request is read, and the
+daemon's stages are spans of the job (``repro.obs``): ``daemon.job`` from
+the request read to the reply sent, holding ``daemon.session``,
+``daemon.admit``, ``daemon.plan``, ``daemon.execute``, ``daemon.encode``
+(digest and outputs as lists) and ``daemon.send`` (JSON and socket).  The
+stages that the reply's ``timings`` report are timed by the same clock
+readings whether the recorder is on or not.
+
 See docs/SERVE.md for the full protocol.
 """
 
@@ -27,6 +35,7 @@ import threading
 import time
 import traceback
 
+from .. import obs
 from ..api import (SCHEMA_VERSION, JobSpec, Session, SpecMismatchError,
                    estimate_job_resources)
 from ..core.bytecode import ProgramFile, iter_record_chunks
@@ -139,21 +148,44 @@ class ServeDaemon:
         with conn, conn.makefile("r", encoding="utf-8") as rf:
             for line in rf:
                 line = line.strip()
-                if not line:
-                    continue
-                try:
-                    resp = self._dispatch(json.loads(line))
-                except Exception as e:     # noqa: BLE001 — protocol boundary
-                    resp = {"ok": False, "error": f"{type(e).__name__}: {e}",
-                            "trace": traceback.format_exc(limit=4)}
-                resp.setdefault("schema_version", SCHEMA_VERSION)
-                try:
-                    conn.sendall((json.dumps(resp) + "\n").encode())
-                except OSError:
+                if line and not self._answer(conn, line):
                     return
-                if resp.get("op") == "shutdown":
-                    self.shutdown()
-                    return
+
+    def _answer(self, conn: socket.socket, line: str) -> bool:
+        """Answer one request line; False once the connection is done."""
+        try:
+            req = json.loads(line)
+            if req.get("op") == "submit":
+                return self._answer_submit(conn, req)
+            resp = self._dispatch(req)
+        except Exception as e:         # noqa: BLE001 — protocol boundary
+            resp = _failure(e)
+        return self._reply(conn, resp)
+
+    def _answer_submit(self, conn: socket.socket, req: dict) -> bool:
+        """A submit, from the request read to the reply sent."""
+        with self._lock:
+            self._job_seq += 1
+            job_id = self._job_seq
+        with obs.job(job_id), obs.timed("daemon.job") as whole:
+            try:
+                resp = self._submit(req, job_id, whole)
+            except Exception as e:     # noqa: BLE001 — protocol boundary
+                resp = _failure(e)
+            with obs.span("daemon.send"):
+                return self._reply(conn, resp)
+
+    def _reply(self, conn: socket.socket, resp: dict) -> bool:
+        """Send one response line; False once the connection is done."""
+        resp.setdefault("schema_version", SCHEMA_VERSION)
+        try:
+            conn.sendall((json.dumps(resp) + "\n").encode())
+        except OSError:
+            return False
+        if resp.get("op") == "shutdown":
+            self.shutdown()
+            return False
+        return True
 
     def _dispatch(self, req: dict) -> dict:
         op = req.get("op")
@@ -163,8 +195,6 @@ class ServeDaemon:
             return {"ok": True, "op": "shutdown"}
         if op == "status":
             return self.status()
-        if op == "submit":
-            return self._submit(req)
         return {"ok": False, "error": f"unknown op {op!r} (expected "
                                       f"submit|status|ping|shutdown)"}
 
@@ -180,7 +210,8 @@ class ServeDaemon:
         with self._lock:
             self._jobs[key] += 1
 
-    def _submit(self, req: dict) -> dict:
+    def _submit(self, req: dict, job_id: int, whole: obs.Stage) -> dict:
+        """One job inside its ``daemon.job`` stage ``whole``."""
         unknown = set(req) - _SUBMIT_FIELDS
         if unknown:
             return {"ok": False,
@@ -191,29 +222,24 @@ class ServeDaemon:
         if self._core_overrides:
             import dataclasses
             spec = dataclasses.replace(spec, **self._core_overrides)
-        with self._lock:
-            self._job_seq += 1
-            job_id = self._job_seq
         self._count("submitted")
-        t_start = time.perf_counter()
         cache = self.cache if req.get("use_cache", True) else None
         try:
             with Session(spec, cache=cache) as sess:
-                frames, mem_bytes = estimate_job_resources(sess)
-                t_admit = time.perf_counter()
+                with obs.span("daemon.session"):
+                    frames, mem_bytes = estimate_job_resources(sess)
                 try:
-                    grant = self.admission.admit(
-                        frames, mem_bytes, queue=req.get("queue", True),
-                        timeout=req.get("timeout"))
+                    with obs.timed("daemon.admit") as admit:
+                        grant = self.admission.admit(
+                            frames, mem_bytes, queue=req.get("queue", True),
+                            timeout=req.get("timeout"))
                 except AdmissionError as e:
                     self._count("rejected")
                     return {"ok": False, "op": "submit", "job_id": job_id,
                             "rejected": True, "error": str(e)}
-                queued_s = time.perf_counter() - t_admit
                 with grant:
-                    t_plan = time.perf_counter()
-                    planned = sess.plan()
-                    plan_s = time.perf_counter() - t_plan
+                    with obs.timed("daemon.plan") as plan:
+                        planned = sess.plan()
                     digests = [program_digest(p) for p in planned]
                     resp = {
                         "ok": True, "op": "submit", "job_id": job_id,
@@ -226,16 +252,14 @@ class ServeDaemon:
                         "frames": frames,
                         "memory_estimate_bytes": mem_bytes,
                         "digests": {"plan": digests},
-                        "timings": {"queued_s": queued_s,
-                                    "plan_s": plan_s},
+                        "timings": {"queued_s": admit.seconds,
+                                    "plan_s": plan.seconds},
                     }
                     if req.get("execute", False):
-                        t_exec = time.perf_counter()
-                        outputs = sess.execute(
-                            check=req.get("check", False))
-                        resp["timings"]["execute_s"] = \
-                            time.perf_counter() - t_exec
-                        resp["outputs_digest"] = _outputs_digest(outputs)
+                        with obs.timed("daemon.execute") as ex:
+                            outputs = sess.execute(
+                                check=req.get("check", False))
+                        resp["timings"]["execute_s"] = ex.seconds
                         if sess.spec.exec_backend == "batched":
                             # batch-schedule sidecar cache outcome; only
                             # batched executes consult that cache kind
@@ -244,11 +268,13 @@ class ServeDaemon:
                         if sess.spec.exec_backend == "overlap":
                             resp["cache"]["overlap"] = \
                                 sess.cache_events.get("overlap", "skipped")
-                        if req.get("return_outputs", False):
-                            resp["outputs"] = {
-                                str(t): v.tolist()
-                                for t, v in outputs.items()}
-            resp["timings"]["total_s"] = time.perf_counter() - t_start
+                        with obs.span("daemon.encode"):
+                            resp["outputs_digest"] = _outputs_digest(outputs)
+                            if req.get("return_outputs", False):
+                                resp["outputs"] = {
+                                    str(t): v.tolist()
+                                    for t, v in outputs.items()}
+            resp["timings"]["total_s"] = whole.elapsed()
             self._count("completed")
             return resp
         except (SpecMismatchError, ValueError, KeyError,
@@ -256,3 +282,9 @@ class ServeDaemon:
             self._count("failed")
             return {"ok": False, "op": "submit", "job_id": job_id,
                     "error": f"{type(e).__name__}: {e}"}
+
+
+def _failure(e: Exception) -> dict:
+    """The reply to a request that raised ``e``, from inside its handler."""
+    return {"ok": False, "error": f"{type(e).__name__}: {e}",
+            "trace": traceback.format_exc(limit=4)}
