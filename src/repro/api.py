@@ -396,6 +396,10 @@ class WorkerScenarios:
 # ---------------------------------------------------------------------------
 
 
+#: plan shapes whose device work ``Session.prepare`` has compiled
+_PREPARED: set[tuple[str, str, str]] = set()
+
+
 class Session:
     """Staged trace→plan→execute/simulate over one JobSpec.
 
@@ -640,6 +644,29 @@ class Session:
         self._cfgs = list(cfgs)
         self.plan_reports = list(reports)
         return True
+
+    def prepare(self) -> None:
+        """Compile ahead the device work that executing the plan will
+        launch, so that ``execute`` compiles nothing: the batched CKKS
+        multiply chain at each group size the batch schedules hold
+        (``exec.batched_ckks.warm``).  Nothing to do on the CPU, for the
+        scalar engine or another protocol, or for a plan shape this
+        process has prepared before."""
+        from .kernels import use_pallas
+        spec = self.spec
+        if self.protocol != "ckks" or spec.exec_backend == "scalar" \
+                or not use_pallas():
+            return
+        key = (spec.plan_hash(self.workload), spec.plan_mode,
+               spec.exec_backend)
+        if key in _PREPARED:
+            return
+        from .exec.batched_ckks import warm
+        planned = self.plan()
+        warm(self.ckks_params(),
+             self._batch_schedules(planned) if spec.exec_backend == "batched"
+             else self._overlap_schedules(planned))
+        _PREPARED.add(key)
 
     # -- stage 3a: execute -----------------------------------------------------
 

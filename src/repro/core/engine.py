@@ -143,6 +143,7 @@ class Engine:
         self._page_shape = page_shape
         # a batched driver runs its scalar calls on the driver it wraps
         self._drv_spans = op_spans(getattr(driver, "inner", driver).name)
+        self._solo_ops = getattr(driver, "solo_ops", frozenset())
 
     # -- helpers ---------------------------------------------------------------
 
@@ -311,11 +312,17 @@ class Engine:
         drv.finalize()
 
     def _exec_rows(self, rec, instrs, rows, batch_ops, on_output) -> None:
-        """A group's rows one by one, in stored order, through
-        ``_exec_one``; those the driver could have batched are counted."""
+        """A group's rows one by one, in stored order: a row whose op the
+        driver takes alone (``solo_ops``) as a batch of one, the rest
+        through ``_exec_one``, counting those the driver could have
+        batched."""
         seq = ((instrs[r] for r in rows) if instrs is not None
                else decode_chunk(rec[rows]))
-        for ins in seq:
+        solo = self._solo_ops if rec is not None else ()
+        for k, ins in enumerate(seq):
+            if ins.op in solo:
+                self._exec_batch(ins.op, rec, rows[k:k + 1])
+                continue
             if ins.op in batch_ops:
                 self.stats.batchable_scalar += 1
             self._exec_one(ins, on_output)

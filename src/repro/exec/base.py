@@ -64,6 +64,9 @@ class BatchedProtocolDriver(ProtocolDriver):
 
     #: ops this driver can execute batched; everything else scalar-delegates
     batch_ops: frozenset = frozenset()
+    #: ops of ``batch_ops`` the engine sends through ``execute_batch`` even
+    #: alone, as a group of one (where the batched path is the faster one)
+    solo_ops: frozenset = frozenset()
 
     def __init__(self, inner: ProtocolDriver):
         self.inner = inner

@@ -12,11 +12,11 @@ public API + layout adapters), ref.py (pure-jnp oracle).  Validated in
 interpret mode on CPU; compiled for the TPU by ``tests/test_tpu_compile.py``
 and run there by ``chip_smoke.py``.
 
-On the main path, the batched CKKS driver sends every ``CT_MUL_NR`` group's
-NTTs through ``ntt/`` when :func:`use_pallas` is true.  The batched GC
-driver sends groups of bare AND/OR instructions through ``garble/``, but no
-registered workload traces such a group yet: their word-level ops run on
-the numpy gates.
+On the main path, the batched CKKS driver runs every ``CT_MUL_NR``, a
+lone one included, through ``ntt/`` when :func:`use_pallas` is true.  The
+batched GC driver sends groups of bare AND/OR instructions through
+``garble/``, but no registered workload traces such a group yet: their
+word-level ops run on the numpy gates.
 
 Interpret-mode selection: compiled ``pallas_call`` cannot lower on the CPU
 backend, so every ops.py entry point defaults ``interpret=None`` and

@@ -8,10 +8,18 @@ same twiddle ordering.
 Each launch counts what crosses between host and device (``repro.obs``):
 ``ntt.h2d_bytes`` for the padded input and the table sent, ``ntt.d2h_bytes``
 for the output read back, and ``ntt.launches`` for the transforms.
+
+``upload``, ``launch`` and ``download`` are the same transforms for a chain
+that stays on the device between launches: one counted upload, launches
+that read nothing back (their tables sent once per prime and ring, and
+counted then), and one counted read-back at the end.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import numpy as np
 
 from ... import obs
@@ -34,6 +42,44 @@ def _launch(a32: np.ndarray, psis32: np.ndarray, **kw) -> np.ndarray:
     obs.count("ntt.h2d_bytes", a32.nbytes + psis32.nbytes)
     out = np.asarray(kernel.ntt_pallas(a32, psis32, **kw))
     obs.count("ntt.d2h_bytes", out.nbytes)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def device_tables(q: int, n: int) -> tuple[jax.Array, jax.Array, int]:
+    """(psis, psis_inv) as uint32 arrays on the device, and n^-1 mod q."""
+    psis, psis_inv, n_inv = ntt_tables(q, n)
+    tables = upload([psis.astype(np.uint32), psis_inv.astype(np.uint32)])
+    return tables[0], tables[1], int(n_inv)
+
+
+def upload(arrays: list[np.ndarray]) -> list[jax.Array]:
+    """Host arrays to the device in one call."""
+    obs.count("ntt.h2d_bytes", sum(a.nbytes for a in arrays))
+    return jax.device_put(arrays)
+
+
+def launch(a: jax.Array, q: int, *, inverse: bool = False,
+           interpret: bool | None = None,
+           block_b: int = 8) -> jax.Array:
+    """One transform of device-resident (B, N) uint32 rows, B a multiple
+    of ``block_b``; the result stays on the device."""
+    with obs.span("ntt.inverse" if inverse else "ntt.forward"):
+        interpret = resolve_interpret(interpret)
+        psis, psis_inv, n_inv = device_tables(q, a.shape[-1])
+        obs.count("ntt.launches", 1)
+        if inverse:
+            return kernel.ntt_pallas(a, psis_inv, q=q, inverse=True,
+                                     n_inv=n_inv, interpret=interpret,
+                                     block_b=block_b)
+        return kernel.ntt_pallas(a, psis, q=q, interpret=interpret,
+                                 block_b=block_b)
+
+
+def download(arrays: list[jax.Array]) -> list[np.ndarray]:
+    """Device arrays back to the host in one call."""
+    out = jax.device_get(arrays)
+    obs.count("ntt.d2h_bytes", sum(o.nbytes for o in out))
     return out
 
 

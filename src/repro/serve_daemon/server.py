@@ -240,6 +240,7 @@ class ServeDaemon:
                 with grant:
                     with obs.timed("daemon.plan") as plan:
                         planned = sess.plan()
+                        sess.prepare()
                     digests = [program_digest(p) for p in planned]
                     resp = {
                         "ok": True, "op": "submit", "job_id": job_id,

@@ -1,6 +1,7 @@
 """exec/ subsystem: batch-schedule structure, batched-vs-scalar digest
 equality across drivers and worker counts, the schedule sidecar cache."""
 
+import collections
 import hashlib
 import os
 from unittest import mock
@@ -8,11 +9,14 @@ from unittest import mock
 import numpy as np
 import pytest
 
+import repro.exec
 from repro.api import JobSpec, Session
-from repro.core.bytecode import (_IMM_OFF, _IN_OFF, _OUT_OFF,
+from repro.core.bytecode import (_IMM_OFF, _IN_OFF, _OUT_OFF, Op,
                                  iter_record_chunks, unpack_heads)
-from repro.exec import build_batch_schedule
+from repro.exec import (BatchedCkksDriver, batched_ckks, build_batch_schedule,
+                        build_overlap_schedule)
 from repro.exec.batching import _BARRIER_OPS, BatchSchedule
+from repro.protocols.ckks import CkksDriver, CkksParams
 
 
 def _digest(outputs) -> str:
@@ -187,6 +191,120 @@ def test_exec_backend_spec_validation():
     with pytest.raises(ValueError, match="exec_backend"):
         JobSpec(workload="sort", n=256, memory_budget=64,
                 exec_backend="vector")
+
+
+# ---------------------------------------------------------------------------
+# CT_MUL_NR: the device chain replays mul_tensor; lone rows by solo_ops
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("count", [1, 2, 9, 33])
+@pytest.mark.parametrize("path", ["numpy", "pallas"])
+def test_batched_ckks_mul_matches_mul_tensor(monkeypatch, path, count):
+    # "pallas": the device chain, its kernels interpreted on the CPU
+    monkeypatch.setattr(batched_ckks, "use_pallas", lambda: path == "pallas")
+    params = CkksParams(n_ring=128, levels=2)
+    drv = BatchedCkksDriver(CkksDriver(params))
+    assert drv.solo_ops == ({Op.CT_MUL_NR} if path == "pallas" else set())
+    ctx, level = drv.inner.ctx, params.levels
+    rng = np.random.default_rng(count)
+    cts = [ctx.encrypt(ctx.encode(rng.uniform(-1, 1, params.n_ring // 2)))
+           for _ in range(2 * count)]
+    ct2, ct3 = cts[0].size, 3 * cts[0].size // 2
+    # operands and results scattered over memory in no particular order
+    slots = rng.permutation(3 * count)
+    a_at, b_at, out_at = (slots[k * count:(k + 1) * count] * ct3
+                          for k in range(3))
+    memory = np.zeros((3 * count * ct3, 1), dtype=np.uint64)
+    for i in range(count):
+        memory[a_at[i]:a_at[i] + ct2, 0] = cts[i].reshape(-1)
+        memory[b_at[i]:b_at[i] + ct2, 0] = cts[count + i].reshape(-1)
+    drv.execute_batch(Op.CT_MUL_NR, (level,), [(out_at, ct3)],
+                      [(a_at, ct2), (b_at, ct2)], memory)
+    for i in range(count):
+        want = ctx.mul_tensor(cts[i], cts[count + i], level)
+        got = memory[out_at[i]:out_at[i] + ct3, 0].reshape(want.shape)
+        assert np.array_equal(got, want), i
+
+
+def _expected_counts(prog, sched, batch_ops, solo) -> tuple[int, int]:
+    """(batchable_scalar, batched_instructions) of one engine's run, from
+    its program and schedule alone: the rows of batched groups are batched,
+    and so are the other rows of a ``solo`` op; the other rows of
+    ``batch_ops`` run alone."""
+    total = collections.Counter(ins.op for ins in prog.instrs)
+    sizes = np.diff(sched.bounds)
+    grouped = collections.Counter()
+    for g in np.flatnonzero((sched.group_op >= 0) & (sizes >= 2)):
+        op = Op(int(sched.group_op[g]))
+        if op in batch_ops:
+            grouped[op] += int(sizes[g])
+    alone = {op: total[op] - grouped[op] for op in batch_ops}
+    lone_solo = sum(alone[op] for op in solo)
+    return (sum(alone.values()) - lone_solo,
+            sum(grouped.values()) + lone_solo)
+
+
+@pytest.mark.parametrize("backend", ["batched", "overlap"])
+@pytest.mark.parametrize("kw,solo", [
+    (dict(workload="n_rmatmul", n=4, ckks_ring=128, memory_budget=0.4),
+     {Op.CT_MUL_NR}),
+    (dict(workload="sort", n=512, memory_budget=64), set()),
+], ids=["ckks-solo", "gc"])
+def test_solo_ops_run_lone_rows_as_batches_of_one(monkeypatch, backend, kw,
+                                                  solo):
+    # a stub CKKS driver declares solo_ops on the CPU; GC declares none
+    make, drivers = repro.exec.make_batched, []
+
+    def declaring(drv):
+        drivers.append(make(drv))
+        if solo:
+            drivers[-1].solo_ops = frozenset(solo)
+        return drivers[-1]
+
+    monkeypatch.setattr(repro.exec, "make_batched", declaring)
+    spec = JobSpec(exec_backend=backend, **kw)
+    with Session(spec) as sess:
+        digest = _digest(sess.execute(check=True))
+        (stats,) = sess.engine_stats
+        prog = sess.plan()[0]
+    build = (build_batch_schedule if backend == "batched"
+             else build_overlap_schedule)
+    sched = build(prog, spec.chunk_instrs)
+    alone, batched = _expected_counts(prog, sched, drivers[0].batch_ops,
+                                      solo)
+    assert (stats.batchable_scalar, stats.batched_instructions) \
+        == (alone, batched)
+    if solo:
+        assert batched > _expected_counts(prog, sched,
+                                          drivers[0].batch_ops, set())[1]
+        assert digest == _outputs(JobSpec(exec_backend="scalar", **kw))[0]
+
+
+def test_prepare_runs_the_chain_once_per_plan_shape(monkeypatch):
+    # as on an accelerator, with the chain's kernels interpreted here
+    import repro.api
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "use_pallas", lambda: True)
+    monkeypatch.setattr(batched_ckks, "resolve_interpret", lambda _: True)
+    monkeypatch.setattr(repro.api, "_PREPARED", set())
+    run, sizes = batched_ckks.mul_tensor_device, []
+
+    def counted(c1, c2, primes):
+        sizes.append(len(c1))
+        return run(c1, c2, primes)
+
+    monkeypatch.setattr(batched_ckks, "mul_tensor_device", counted)
+    spec = JobSpec(workload="n_rmatmul", n=4, ckks_ring=128,
+                   memory_budget=0.4, exec_backend="batched")
+    for _ in range(2):
+        with Session(spec) as sess:
+            sess.prepare()
+            prog = sess.plan()[0]
+    sched = build_batch_schedule(prog, spec.chunk_instrs)
+    groups = np.diff(sched.bounds)[sched.group_op == int(Op.CT_MUL_NR)]
+    assert sizes == sorted({batched_ckks.stacked_rows(int(c))
+                            for c in [1, *groups]})
 
 
 # ---------------------------------------------------------------------------

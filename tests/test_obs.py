@@ -6,8 +6,8 @@ spans that carry the job's id on every thread and nest, counters that agree
 with the engine's own counts and with a count of the NTT kernel's launches
 taken from outside, and ``storage.wait`` only inside a swap directive.
 
-CPU, ring N=128; the batched CKKS driver's NTTs go through the Pallas
-kernel in interpret mode, so that launches happen.
+CPU, ring N=128; the batched CKKS driver's multiplies take its device
+chain, with the Pallas kernels in interpret mode, so that launches happen.
 """
 
 import sys
@@ -20,9 +20,10 @@ from repro import obs
 from repro.api import JobSpec, Session
 from repro.core.engine import Engine
 from repro.core.storage import AsyncIO
-from repro.exec.batched_ckks import BatchedCkksDriver
+from repro.exec import batched_ckks
 from repro.kernels.ntt import kernel
 from repro.kernels.ntt import ops as ntt_ops
+from repro.protocols.ckks.params import CkksParams
 from repro.serve_daemon.client import serve_client
 from repro.serve_daemon.server import ServeDaemon
 
@@ -56,19 +57,21 @@ def daemon(tmp_path):
 
 @pytest.fixture()
 def launches(monkeypatch):
-    """NTTs of the batched CKKS driver through the kernel, each launch's
-    shape taken by wrapping ``kernel.ntt_pallas``."""
+    """Multiplies of the batched CKKS driver on its device chain, each
+    launch's shape and direction taken by wrapping ``kernel.ntt_pallas``.
+    The chain's twiddle tables are on the device before the job starts."""
     shapes = []
     orig = kernel.ntt_pallas
 
     def counted(a, *args, **kw):
-        shapes.append(a.shape)
+        shapes.append((*a.shape, kw.get("inverse", False)))
         return orig(a, *args, **kw)
 
     monkeypatch.setattr(kernel, "ntt_pallas", counted)
-    monkeypatch.setattr(BatchedCkksDriver, "_ntt", lambda self: (
-        lambda a, q: ntt_ops.ntt_forward(a, q, interpret=True),
-        lambda a, q: ntt_ops.ntt_inverse(a, q, interpret=True)))
+    monkeypatch.setattr(batched_ckks, "use_pallas", lambda: True)
+    for q in CkksParams(n_ring=SPEC["ckks_ring"],
+                        levels=SPEC["ckks_levels"]).primes:
+        ntt_ops.device_tables(q, SPEC["ckks_ring"])
     return shapes
 
 
@@ -164,8 +167,9 @@ def test_on_one_job_on_two_workers(recorder, daemon, launches, monkeypatch):
     names = {s.name for s in rec.spans}
     assert {"daemon.job", "daemon.session", "daemon.admit", "daemon.plan",
             "daemon.execute", "daemon.encode", "daemon.send", "engine.run",
-            "ckks.CT_MUL_NR", "batched.CT_MUL_NR", "ntt.forward",
+            "ckks.CT_RELIN", "batched.CT_MUL_NR", "ntt.forward",
             "ntt.inverse"} <= names
+    assert "ckks.CT_MUL_NR" not in names      # every multiply on the chain
 
     # every span is the job's, on the engine threads too
     assert {s.job for s in rec.spans} == {job}
@@ -199,10 +203,13 @@ def test_on_one_job_on_two_workers(recorder, daemon, launches, monkeypatch):
     assert alone > 0 and batched > 0
     assert alone + batched == sum(batchable)
     counts = {name: n for (j, name), n in rec.counts.items() if j == job}
+    # each chain uploads its forward launches' rows and reads back its
+    # inverse launches' rows, once each
     assert counts["ntt.launches"] == len(launches) > 0
-    assert counts["ntt.h2d_bytes"] == sum(b * n * 4 + n * 4
-                                          for b, n in launches)
-    assert counts["ntt.d2h_bytes"] == sum(b * n * 4 for b, n in launches)
+    assert counts["ntt.h2d_bytes"] == sum(b * n * 4
+                                          for b, n, inv in launches if not inv)
+    assert counts["ntt.d2h_bytes"] == sum(b * n * 4
+                                          for b, n, inv in launches if inv)
 
 
 def test_storage_wait_only_inside_a_swap_directive(recorder, monkeypatch):

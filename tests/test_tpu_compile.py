@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.exec import batched_ckks
 from repro.kernels.garble import kernel as gk
 from repro.kernels.ntt import kernel as nk
 from repro.protocols.ckks.params import CkksParams
@@ -82,3 +83,27 @@ def test_pointwise_kernel_compiles(one_chip, n):
                          [((NTT_BLOCK, n), U32), ((NTT_BLOCK, n), U32)],
                          one_chip, q=q)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("count", [1, 58])
+def test_mul_chain_shapes_compile(one_chip, count):
+    # the batched multiply's device chain at ring 4096: the stacked forward
+    # and inverse launches and the product step, for a lone multiply and
+    # for the group of 58 of the resident n_rmatmul plan (chains of 16)
+    n = 4096
+    q = CkksParams(n_ring=n).primes[0]
+    m = batched_ckks.stacked_rows(count)
+    rows_in = (4 * m, n)
+    rows_out = jax.eval_shape(
+        lambda f: batched_ckks.tensor_products(f, q=q, m=m, interpret=True),
+        jax.ShapeDtypeStruct(rows_in, U32)).shape
+    assert rows_out == rows_in
+    texts = [
+        _compile_text(nk.ntt_pallas, [(rows_in, U32), ((n,), U32)],
+                      one_chip, q=q),
+        _compile_text(batched_ckks.tensor_products, [(rows_in, U32)],
+                      one_chip, q=q, m=m),
+        _compile_text(nk.ntt_pallas, [(rows_out, U32), ((n,), U32)],
+                      one_chip, q=q, inverse=True, n_inv=1),
+    ]
+    assert all("tpu_custom_call" in t for t in texts)
