@@ -2,12 +2,13 @@
 
     python3 perfbench/calibrate.py --workload nmatmul.b40 --seeds 12 [--first S]
 
-Sets the cell up once, then for each seed serves one job through the
-window's path and reads the widest gap of its outputs against the float64
-reference (the program's reading), and the same gap of the float16
-reference on that job's inputs (the control's reading).  Prints one JSON
-line per seed and a summary: the largest program reading, the smallest
-control reading, and the limit in force.  Needs the chip, as a run does;
+For a configuration whose reference is ``einsum``.  Sets the cell up
+once, then for each seed serves one job through the window's path and
+reads the widest gap of its outputs against the float64 reference (the
+program's reading), and the same gap of the float16 reference on that
+job's inputs (the control's reading).  Prints one JSON line per seed and
+a summary: the largest program reading, the smallest control reading,
+and the limit in force.  Needs the chip, as a run does;
 the benchmark's own runs never call this.
 """
 
@@ -36,7 +37,6 @@ def main(argv=None) -> int:
     import harness
     import judge
     import numpy as np
-    import reference
     c = cell.load(args.workload)
     device.require(c.chips)
     rows = []
@@ -47,11 +47,10 @@ def main(argv=None) -> int:
             win = stand.drive(1e-3)             # one job
             program = stand.checks(win)
             job = win.jobs[0]
-            want = reference.outputs(c.config["output"],
-                                     stand.inputs.arrays(job["index"]))
-            ctl = reference.outputs(c.config["output"],
-                                    stand.inputs.arrays(job["index"]),
-                                    np.float16)
+            want = stand.inputs.expected(job["index"])
+            ctl = stand.inputs.ref.outputs(c.config["output"],
+                                           stand.inputs.arrays(job["index"]),
+                                           np.float16)
             control, _ = judge.max_gap(want, ctl)
             row = {"seed": seed, "program": program["max_err"]["value"],
                    "control": control, "correct": judge.passed(program),

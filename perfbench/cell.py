@@ -1,9 +1,11 @@
 """A cell of BENCHMARK.json, with every file it names found by name.
 
 A cell names a configuration (``configs[].file``) and a traffic mix
-(``traffic/<traffic>.json``).  Its per-layer metrics are the entries of
-``per_layer`` whose ``workloads`` list it (or that list none), each read by
-``metrics/<name>.py``.  Nothing here knows any particular cell.
+(``traffic/<traffic>.json``).  The configuration's plain reference is
+``references/<reference>.py`` (``einsum`` where it names none).  Its
+per-layer metrics are the entries of ``per_layer`` whose ``workloads``
+list it (or that list none), each read by ``metrics/<name>.py``.  Nothing
+here knows any particular cell.
 """
 
 from __future__ import annotations
@@ -54,11 +56,23 @@ def load(name: str, root: str = ROOT) -> Cell:
                            if _applies(m, name)])
 
 
-def reader(metric: str):
-    """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
-    path = os.path.join(HERE, "metrics", metric + ".py")
+def _plugin(kind: str, name: str):
+    """The module ``<kind>/<name>.py`` of the benchmark."""
+    path = os.path.join(HERE, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + metric.replace(".", "_"), path)
+        f"perfbench_{kind}_" + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str):
+    """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
+    return _plugin("metrics", metric).read
+
+
+def reference(config: dict):
+    """The plain reference a configuration names: ``arrays``,
+    ``provider``, ``expected``, ``COMPARE`` and, where an exact comparison
+    needs one, ``canonical``."""
+    return _plugin("references", config.get("reference", "einsum"))

@@ -2,13 +2,13 @@
 
 Set-up brings up the chip, starts a ``ServeDaemon`` in this process on a
 unix socket, registers the cell's workload under a name of the
-benchmark's own with the seeded generator as its input provider, warms
-trace, plan and batch schedule into the daemon's artifact cache, and
-compiles the kernel shapes that schedule holds.  The window is one client
-in a closed loop: ``submit`` with ``execute`` and ``return_outputs`` on,
-job after job until ``seconds`` have passed; the job running then is
-allowed to finish.  After the window every job's outputs are compared with
-the plain reference (``judge``).
+benchmark's own with the configuration's seeded reference as its input
+provider, warms trace, plan and batch schedule into the daemon's artifact
+cache, and compiles the kernel shapes that schedule holds.  The window is
+one client in a closed loop: ``submit`` with ``execute`` and
+``return_outputs`` on, job after job until ``seconds`` have passed; the
+job running then is allowed to finish.  After the window every job's
+outputs are compared with the plain reference (``judge``).
 """
 
 from __future__ import annotations
@@ -19,6 +19,7 @@ import os
 import shutil
 import sys
 import tempfile
+import threading
 import time
 
 import numpy as np
@@ -37,31 +38,46 @@ def log(*parts) -> None:
 class Workload:
     """The cell's workload, registered as ``perfbench.<config>``: the
     program's own DSL program and page size, with inputs drawn per job
-    from the seed.  Job ``k`` is the ``k``-th engine run that asks."""
+    from the seed.  ``drive`` says which job it submits (``start``); the
+    first rank of the job that asks draws that job's values, every other
+    rank gets the same ones, and ``drawn`` lists the job each rank got."""
 
     def __init__(self, config: dict, inputs: Inputs):
         from repro.workloads import get, register
         base = get(config["job"]["workload"])
         self.name = "perfbench." + config["name"]
         self.inputs = inputs
-        self.issued = 0             # input draws so far: job k draws k
+        self.job = -1               # the job being submitted
+        self.drawn: list[int] = []
+        self._values = None         # (job, provider) of the last draw
+        self._lock = threading.Lock()
         register(dataclasses.replace(base, name=self.name,
                                      inputs=self._provider))
 
+    def start(self) -> int:
+        """The next job's index, which every rank that asks draws."""
+        self.job += 1
+        self.drawn = []
+        return self.job
+
     def _provider(self, n, worker, num_workers, **extra):
-        self.issued += 1
-        return self.inputs.provider(self.issued - 1)
+        with self._lock:
+            if self._values is None or self._values[0] != self.job:
+                self._values = (self.job, self.inputs.provider(self.job))
+            self.drawn.append(self._values[0])
+            return self._values[1]
 
 
 def warm_kernels(spec, schedules) -> list[tuple[int, int]]:
     """Compile the NTT launches the batch schedules hold: each CT_MUL_NR
     group of two or more, for every prime of the chain, both directions.
-    Returns the (group size, ring) pairs warmed."""
+    Returns the (group size, ring) pairs warmed: none on the CPU, whose
+    numpy NTT compiles nothing, and none for a spec with no CKKS ring."""
     from repro.core.bytecode import Op
     from repro.kernels import use_pallas
     from repro.kernels.ntt import ops
     from repro.protocols.ckks.params import CkksParams
-    if not use_pallas():            # the CPU's numpy NTT compiles nothing
+    if not use_pallas() or spec.ckks_ring is None:
         return []
     n = int(spec.ckks_ring)
     primes = CkksParams(n_ring=n, levels=int(spec.ckks_levels)).primes
@@ -102,13 +118,18 @@ class Window:
 
 def drive(client, spec, workload: Workload, seconds: float,
           span) -> Window:
+    """Closed-loop jobs for ``seconds``.  Each job's outputs are kept as
+    floats, or as exact integers where the reference compares exactly."""
+    from repro.api import driver_parties
     from repro.serve_daemon.client import ServeError
+    ranks = driver_parties(spec.normalized().driver) * spec.num_workers
+    kind = object if workload.inputs.compare == "exact" else np.float64
     jobs, failed = [], 0
     t_first = time.perf_counter()
     t_last = t_first
     while t_last - t_first < seconds:
         t_sub = time.perf_counter()
-        before = workload.issued
+        k = workload.start()
         try:
             with span(tracing.CLIENT):
                 resp = client.submit(spec, execute=True,
@@ -118,12 +139,13 @@ def drive(client, spec, workload: Workload, seconds: float,
             failed += 1
             break
         t_last = time.perf_counter()
-        if workload.issued != before + 1:
-            raise RuntimeError("a job drew no inputs, or more than once")
+        if workload.drawn != [k] * ranks:
+            raise RuntimeError(f"job {k}: its {ranks} ranks drew inputs "
+                               f"of jobs {workload.drawn}")
         jobs.append({
-            "index": before, "seconds": t_last - t_sub,
+            "index": k, "seconds": t_last - t_sub,
             "timings": resp["timings"],
-            "outputs": {int(t): np.asarray(v, np.float64)
+            "outputs": {int(t): np.asarray(v, kind)
                         for t, v in resp.get("outputs", {}).items()}})
     return Window(jobs, failed, t_first, t_last)
 
@@ -222,11 +244,11 @@ class Stand:
     def drive(self, seconds: float, span=contextlib.nullcontext) -> Window:
         return drive(self.client, self.spec, self.workload, seconds, span)
 
-    def checks(self, win: Window, dtype=np.float64) -> dict:
+    def checks(self, win: Window) -> dict:
         failed = win.failed + (0 if win.jobs else 1)
         return judge.checks(self.cell.config, self.cell.traffic, win.jobs,
                             self.inputs, self.watch.engines,
-                            self.watch.executes, failed, dtype)
+                            self.watch.executes, failed)
 
     def stop_serving(self) -> None:
         if self.client is not None:

@@ -2,9 +2,17 @@
 and the guarantees the configuration and the mix state.
 
 Each number compared has a limit of its own; PERF.md gives the readings
-each limit was set from.  ``max_err`` is the widest gap between a
-decrypted output slot and the float64 reference over every job of the
-window (its limit is the configuration's); the others are exact:
+each limit was set from.  The configuration's reference says how outputs
+are compared (``COMPARE``):
+
+* ``max_err``      the widest gap between a decrypted output slot and the
+                   float64 reference over every job of the window (its
+                   limit is the configuration's);
+* ``mismatched``   where the comparison is exact: outputs that differ
+                   from the reference's, each taken in the reference's
+                   ``canonical`` form (limit 0).
+
+The others are exact:
 
 * ``missing``      outputs the reference has and a job did not return;
 * ``failed``       jobs the daemon answered with an error;
@@ -18,8 +26,6 @@ window (its limit is the configuration's); the others are exact:
 from __future__ import annotations
 
 import numpy as np
-
-from reference import outputs as reference_outputs
 
 
 def frame_limit(config: dict, traffic: dict) -> int:
@@ -41,22 +47,43 @@ def max_gap(expected: dict, got: dict) -> tuple[float, int]:
     return worst, missing
 
 
+def mismatches(expected: dict, got: dict) -> tuple[int, int]:
+    """(outputs whose values differ, outputs missing), compared as exact
+    integers."""
+    differ, missing = 0, 0
+    for tag, want in expected.items():
+        have = got.get(tag)
+        if have is None or np.shape(have) != np.shape(want):
+            missing += 1
+        elif not np.array_equal(np.asarray(have).astype(object),
+                                np.asarray(want).astype(object)):
+            differ += 1
+    return differ, missing
+
+
 def checks(config: dict, traffic: dict, jobs: list[dict], inputs,
-           engines: list[dict], executes: list[dict], failed: int,
-           dtype=np.float64) -> dict:
+           engines: list[dict], executes: list[dict], failed: int) -> dict:
     """{name: {"value", "limit"}} over the window's jobs; ``jobs[i]`` has
     ``index`` (its input draw) and ``outputs`` (tag -> values)."""
-    worst, missing = 0.0, 0
+    exact = inputs.compare == "exact"
+    worst, differ, missing = 0.0, 0, 0
     for job in jobs:
-        want = reference_outputs(config["output"],
-                                 inputs.arrays(job["index"]), dtype)
-        gap, miss = max_gap(want, job["outputs"])
-        worst = gap if not gap <= worst else worst
+        want = inputs.expected(job["index"])
+        if exact:
+            bad, miss = mismatches(inputs.canonical(want),
+                                   inputs.canonical(job["outputs"]))
+            differ += bad
+        else:
+            gap, miss = max_gap(want, job["outputs"])
+            worst = gap if not gap <= worst else worst
         missing += miss
-    out = {"max_err": {"value": worst,
-                       "limit": float(config["limits"]["max_err"])},
-           "missing": {"value": missing, "limit": 0},
-           "failed": {"value": failed, "limit": 0}}
+    if exact:
+        out = {"mismatched": {"value": differ, "limit": 0}}
+    else:
+        out = {"max_err": {"value": worst,
+                           "limit": float(config["limits"]["max_err"])}}
+    out.update({"missing": {"value": missing, "limit": 0},
+                "failed": {"value": failed, "limit": 0}})
     guarantee = traffic["guarantee"]
     if guarantee == "frame_budget":
         held = max((e["pages"] + e["prefetch_pages"] for e in engines),
